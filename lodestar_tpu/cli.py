@@ -437,9 +437,13 @@ async def _run_beacon(args) -> int:
     verifier = None
     mode = args.bls_verifier
     if mode == "auto":
-        import jax
+        from .logger import get_logger
+        from .utils import chip
 
-        mode = "tpu" if jax.default_backend() == "tpu" else "oracle"
+        mode = "tpu" if chip.on_tpu() else "oracle"
+        get_logger("cli").warn(
+            "--bls-verifier auto chose a verifier", {"verifier": mode}
+        )
     if mode == "tpu":
         from .bls import TpuBlsVerifier
         from .bls import kernels as _bls_kernels

@@ -14,17 +14,11 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import tempfile
-from pathlib import Path
 
-_SRC = Path(__file__).resolve().parents[3] / "csrc" / "bls381.c"
-_HDR = Path(__file__).resolve().parents[3] / "csrc" / "bls381_constants.h"
-_LIB_DIR = Path(
-    os.environ.get(
-        "LODESTAR_TPU_NATIVE_DIR",
-        Path.home() / ".cache" / "lodestar_tpu" / "native",
-    )
-)
+from ...utils import native_build
+
+_SRC = native_build.REPO / "csrc" / "bls381.c"
+_HDR = native_build.REPO / "csrc" / "bls381_constants.h"
 
 _lib = None
 _load_failed = False
@@ -41,26 +35,9 @@ def _load():
     if _lib is not None or _load_failed:
         return _lib
     try:
-        _LIB_DIR.mkdir(parents=True, exist_ok=True)
-        mtime = int(_SRC.stat().st_mtime) ^ int(_HDR.stat().st_mtime)
-        path = _LIB_DIR / f"bls381_{mtime}.so"
-        if not path.exists():
-            with tempfile.TemporaryDirectory() as td:
-                tmp = Path(td) / "lib.so"
-                subprocess.run(
-                    [
-                        os.environ.get("CC", "cc"),
-                        "-O2",
-                        "-shared",
-                        "-fPIC",
-                        str(_SRC),
-                        "-o",
-                        str(tmp),
-                    ],
-                    check=True,
-                    capture_output=True,
-                )
-                os.replace(tmp, path)
+        path = native_build.build(
+            _SRC, [os.environ.get("CC", "cc"), "-O2"], deps=(_HDR,)
+        )
         lib = ctypes.CDLL(str(path))
         for name, res in (
             ("blsn_g1_decompress", ctypes.c_int),
@@ -73,7 +50,7 @@ def _load():
         ):
             getattr(lib, name).restype = res
         _lib = lib
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         _load_failed = True
         _lib = None
     return _lib

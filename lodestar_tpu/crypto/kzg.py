@@ -52,6 +52,7 @@ from pathlib import Path
 from . import bls as _bls  # noqa: F401  (package init side effects)
 from .bls import curve as oc
 from .bls import native
+from ..utils import chip, native_build
 
 BLS_MODULUS = (
     0x73EDA753299D7D483339D80809A1D80553BDA402FFFE5BFEFFFFFFFF00000001
@@ -371,9 +372,7 @@ def _evaluate_polynomials_batch(
     mode = _fr_backend
     use_device = mode == "device"
     if mode == "auto":
-        import jax
-
-        use_device = jax.default_backend() == "tpu"
+        use_device = chip.on_tpu()
     if use_device and _device_blocked("kzg_fr"):
         use_device = False  # quarantined: Python ints serve exactly
     if use_device:
@@ -439,9 +438,7 @@ def _device_msm_ready(n: int) -> bool:
     BLS verifier's host_fallback_when_cold keeps cold buckets off
     multi-minute compiles."""
     global _MSM_DEVICE_FALLBACKS
-    import jax
-
-    if jax.default_backend() != "tpu":
+    if not chip.on_tpu():
         return False
     from ..ops import msm as _msm
 
@@ -589,13 +586,7 @@ def dev_trusted_setup(cache_dir: str | None = None) -> TrustedSetup:
     against this setup — tests and dev chains only. Production must
     `load_trusted_setup` with the ceremony output.
     """
-    d = Path(
-        cache_dir
-        or os.environ.get(
-            "LODESTAR_TPU_NATIVE_DIR",
-            Path.home() / ".cache" / "lodestar_tpu" / "native",
-        )
-    )
+    d = Path(cache_dir) if cache_dir else native_build.LIB_DIR
     d.mkdir(parents=True, exist_ok=True)
     cache = d / f"dev_trusted_setup_{FIELD_ELEMENTS_PER_BLOB}.json"
     if cache.exists():

@@ -17,17 +17,12 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import tempfile
 from hashlib import sha256
 from pathlib import Path
 
-_SRC = Path(__file__).resolve().parents[2] / "csrc" / "sha256_merkle.c"
-_LIB_DIR = Path(
-    os.environ.get(
-        "LODESTAR_TPU_NATIVE_DIR",
-        Path.home() / ".cache" / "lodestar_tpu" / "native",
-    )
-)
+from ..utils import native_build
+
+_SRC = native_build.REPO / "csrc" / "sha256_merkle.c"
 
 _lib = None
 AVAILABLE = False
@@ -43,29 +38,8 @@ _ZERO_BUF = b"".join(_ZERO)
 
 def _build() -> Path | None:
     try:
-        _LIB_DIR.mkdir(parents=True, exist_ok=True)
-        src_mtime = int(_SRC.stat().st_mtime)
-        lib_path = _LIB_DIR / f"sha256_merkle_{src_mtime}.so"
-        if lib_path.exists():
-            return lib_path
-        with tempfile.TemporaryDirectory() as td:
-            tmp = Path(td) / "lib.so"
-            subprocess.run(
-                [
-                    os.environ.get("CC", "cc"),
-                    "-O3",
-                    "-shared",
-                    "-fPIC",
-                    str(_SRC),
-                    "-o",
-                    str(tmp),
-                ],
-                check=True,
-                capture_output=True,
-            )
-            os.replace(tmp, lib_path)
-        return lib_path
-    except Exception:
+        return native_build.build(_SRC, [os.environ.get("CC", "cc"), "-O3"])
+    except (OSError, subprocess.SubprocessError):
         return None
 
 

@@ -11,17 +11,11 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
-import tempfile
 from pathlib import Path
 
-_SRC = Path(__file__).resolve().parents[2] / "csrc" / "kvstore.cc"
-_LIB_DIR = Path(
-    os.environ.get(
-        "LODESTAR_TPU_NATIVE_DIR",
-        Path.home() / ".cache" / "lodestar_tpu" / "native",
-    )
-)
+from ..utils import native_build
+
+_SRC = native_build.REPO / "csrc" / "kvstore.cc"
 
 OP_PUT = 1
 OP_DEL = 2
@@ -94,27 +88,9 @@ def _load_lib():
     global _lib
     if _lib is not None:
         return _lib
-    _LIB_DIR.mkdir(parents=True, exist_ok=True)
-    src_mtime = int(_SRC.stat().st_mtime)
-    lib_path = _LIB_DIR / f"kvstore_{src_mtime}.so"
-    if not lib_path.exists():
-        with tempfile.TemporaryDirectory() as td:
-            tmp = Path(td) / "lib.so"
-            subprocess.run(
-                [
-                    os.environ.get("CXX", "c++"),
-                    "-O2",
-                    "-std=c++17",
-                    "-shared",
-                    "-fPIC",
-                    str(_SRC),
-                    "-o",
-                    str(tmp),
-                ],
-                check=True,
-                capture_output=True,
-            )
-            os.replace(tmp, lib_path)
+    lib_path = native_build.build(
+        _SRC, [os.environ.get("CXX", "c++"), "-O2", "-std=c++17"]
+    )
     lib = ctypes.CDLL(str(lib_path))
     lib.kv_open.restype = ctypes.c_void_p
     lib.kv_open.argtypes = [ctypes.c_char_p]

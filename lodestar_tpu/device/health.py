@@ -573,42 +573,21 @@ def default_ladder_shrink() -> bool:
     return True
 
 
-def make_device_probe(executor=None, bucket: int = 4,
-                      timeout_s: float = 30.0):
+def make_device_probe(executor=None, timeout_s: float = 30.0):
     """Build the known-answer probe: one real staged verify at the
-    smallest rung (valid synthetic sets — the answer is True by
-    construction), dispatched maintenance-class through the executor
-    when one is wired (with an explicit per-job watchdog deadline so
-    a still-hung device fails the probe instead of wedging it)."""
+    smallest rung of the live ladder — the rung the per-set retries
+    dispatch, so its programs are compiled — over valid synthetic
+    sets (the answer is True by construction), dispatched
+    maintenance-class through the executor when one is wired (with an
+    explicit per-job watchdog deadline so a still-hung device fails
+    the probe instead of wedging it)."""
 
     def probe() -> bool:
         def dispatch() -> bool:
-            import jax.numpy as jnp
-
             from ..bls import kernels
-            from ..crypto.bls import curve as oc
-            from ..ops import curve as C
 
-            n = bucket
-            hs = [oc.g2_mul(oc.G2_GEN, 11 + i) for i in range(n)]
-            pks, sigs = [], []
-            for i, h in enumerate(hs):
-                sk = 17 + i
-                pks.append(oc.g1_mul(oc.G1_GEN, sk))
-                sigs.append(oc.g2_mul(h, sk))
-            pk_dev = C.g1_batch_from_ints(pks)
-            h_pt = C.g2_batch_from_ints(hs)
-            sig_dev = C.g2_batch_from_ints(sigs)
-            bits = C.scalars_to_bits(
-                [(0x51D5 + 2 * i) | 1 for i in range(n)],
-                kernels.RAND_BITS,
-            )
-            mask = jnp.ones(n, bool)
-            return bool(
-                kernels.run_verify_batch_async(
-                    pk_dev, (h_pt.x, h_pt.y), sig_dev, bits, mask
-                )
-            )
+            args = kernels.host_batch_args(kernels.bucket_size(1))
+            return bool(kernels.run_verify_batch_async(*args))
 
         if executor is None:
             return dispatch()

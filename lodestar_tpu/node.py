@@ -28,6 +28,7 @@ from .network.processor import NetworkProcessor
 from .network.reqresp import InProcessTransport, ReqResp
 from .params import ForkSeq, preset
 from .sync import RangeSync, SyncServer
+from .utils import chip
 
 
 class BeaconNode:
@@ -396,9 +397,7 @@ class BeaconNode:
                 warmup_kick=warm_kick,
                 logger=get_logger("device-health"),
             )
-            import jax as _jax
-
-            on_accel = _jax.default_backend() != "cpu"
+            on_accel = chip.on_tpu()
             # warmup suspends while quarantined; kzg MSM/Fr ride
             # their host tiers; the verifier's buckets short-circuit
             # to the bit-identical host oracle
@@ -527,12 +526,10 @@ class BeaconNode:
         # only where the auto backend will actually route them (TPU);
         # until a rung is warm, lincombs ride the host C Pippenger
         # (counted as lodestar_kzg_msm_device_fallback_total)
-        import jax as _jax
-
         if (
             node.bls_warmup
             and _kzg.msm_backend() in ("auto", "device")
-            and _jax.default_backend() == "tpu"
+            and chip.on_tpu()
         ):
             import threading
 

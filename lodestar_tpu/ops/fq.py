@@ -22,6 +22,7 @@ import numpy as np
 from ..crypto.bls.fields import P
 from . import limbs as L
 from .limbs import Lv
+from ..utils import chip
 
 add = L.add
 sub = L.sub
@@ -71,13 +72,10 @@ def pow_const(a: Lv, e: int) -> Lv:
     assert e >= 0
     if e == 0:
         return const(1, a.v.shape[:-1])
-    if e > 1 and a.v.ndim == 2:
-        import jax as _jax
+    if e > 1 and a.v.ndim == 2 and chip.on_tpu():
+        from . import pallas_chain
 
-        if _jax.default_backend() == "tpu":
-            from . import pallas_chain
-
-            return pallas_chain.pow_const(a, e)
+        return pallas_chain.pow_const(a, e)
     bits = jnp.asarray(
         np.array([(e >> i) & 1 for i in range(e.bit_length())], np.bool_)
     )

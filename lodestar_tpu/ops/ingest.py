@@ -42,6 +42,7 @@ from . import curve as C
 from . import fq
 from . import limbs as L
 from . import tower
+from ..utils import chip
 
 # BLS parameter |x| (the curve's generator parameter, not a coordinate)
 X_ABS = 0xD201000000010000
@@ -230,7 +231,7 @@ def _mul_x(p: C.JacPoint, batch) -> C.JacPoint:
     jacobian state through HBM on all 64 steps and measured ~550 ms at
     batch 2048 (two of them dominated the cofactor stage, round-4
     profile). Elsewhere: the jacobian scan ladder."""
-    if jax.default_backend() == "tpu" and len(tuple(batch)) == 1:
+    if chip.on_tpu() and len(tuple(batch)) == 1:
         from . import pallas_ladder as PL
 
         ax, ay = _jac_to_affine(p)
@@ -263,7 +264,7 @@ def g2_in_subgroup(p: C.JacPoint, batch) -> jax.Array:
     """psi(Q) == [x]Q (Bowe's fast check; csrc analog). Callers pass
     an AFFINE-constructed point (jac_from_affine), so on TPU the |x|
     ladder runs as the fused Pallas kernel."""
-    if jax.default_backend() == "tpu" and len(tuple(batch)) == 1:
+    if chip.on_tpu() and len(tuple(batch)) == 1:
         from . import pallas_ladder as PL
 
         xq = jac_neg(
@@ -520,7 +521,7 @@ def sswu_iso_sum(u0, u1) -> C.JacPoint:
     with 1-D batches the field core runs as the fused Pallas kernels
     (ops/pallas_sswu.py)."""
     if (
-        jax.default_backend() == "tpu"
+        chip.on_tpu()
         and u0[0].v.ndim == 2
     ):
         return _sswu_iso_sum_tpu(u0, u1)

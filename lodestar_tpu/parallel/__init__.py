@@ -72,9 +72,12 @@ def whole_bucket_verify(mesh: Mesh, local_fn, n_args: int,
     leading axis across the mesh; indices in `replicated_args` get P()
     (e.g. the shared same-message hash point). The caller places
     inputs with shard_batch/replicate to match.
-    """
-    from jax.experimental.shard_map import shard_map
 
+    The last argument is the bucket's live-set mask. A shard past the
+    last live set holds padding only: it has nothing to verify and
+    counts as good, whatever `local_fn` makes of an empty batch (the
+    BLS bodies pair its aggregate, the point at infinity, and fail).
+    """
     import jax.numpy as jnp
 
     in_specs = tuple(
@@ -83,19 +86,19 @@ def whole_bucket_verify(mesh: Mesh, local_fn, n_args: int,
     )
 
     def spmd(*args):
-        ok = local_fn(*args)
+        ok = jnp.logical_or(local_fn(*args), ~jnp.any(args[-1]))
         bad = jax.lax.psum(jnp.where(ok, 0, 1), BATCH_AXIS)
         return bad == 0
 
-    # check_rep=False: the replication-type checker mis-infers the
-    # carry replication of lax.scan bodies (the ladders and masked
-    # products are scan-based) and rejects the program; the body is
-    # collective-free by construction and the one explicit psum above
-    # is the whole cross-shard story, so the check adds nothing here.
-    return shard_map(
+    # check_vma=False: the varying-axes checker mis-infers the carry
+    # of lax.scan bodies (the ladders and masked products are
+    # scan-based) and rejects the program; the body is collective-free
+    # by construction and the one explicit psum above is the whole
+    # cross-shard story, so the check adds nothing here.
+    return jax.shard_map(
         spmd,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

@@ -1053,6 +1053,9 @@ async def device_loss_under_load(ctx: ScenarioContext) -> None:
     # test runner's patience (the watchdog rides asyncio.wait_for, so
     # the injected ManualClock only drives the probe backoff)
     verifier.attach_health(tracker, wave_timeout_s=0.35)
+    # the device served before it hung: a wave that compiles its
+    # program is not watched, so the drill's rung is compiled first
+    await verifier.verify_signature_sets(mk_sets(n_calls))
     injector = ctx.registry.track(device_hang())
     try:
         results: list[bool] = []

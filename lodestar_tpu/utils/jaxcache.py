@@ -5,40 +5,44 @@ final exponentiation); on CPU XLA that is tens of seconds per shape.
 A persistent on-disk cache makes every process after the first start
 warm — the analog of the reference paying its worker-spawn cost once
 at startup (chain/bls/multithread/index.ts:130-146).
+
+Where `JAX_COMPILATION_CACHE_DIR` is set, JAX takes the directory from
+it and this module sets none; otherwise the cache lives at the fixed
+`DEFAULT_DIR`, so that every process of a checkout finds it.
 """
 
 from __future__ import annotations
 
 import os
 
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(__file__))),
+    ".jax_cache",
+)
+
 _enabled = False
 
 
-def enable(cache_dir: str | None = None) -> None:
+def enable() -> None:
     global _enabled
     if _enabled:
         return
     import jax
 
-    d = cache_dir or os.environ.get(
-        "LODESTAR_TPU_JAX_CACHE",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(__file__))), ".jax_cache"),
-    )
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_DIR
     try:
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
+        if d == DEFAULT_DIR:
+            os.makedirs(d, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         # jax latches "no persistent cache" at the process's FIRST
         # compile; a host that jitted anything before enable() would
         # silently never cache without this re-init
-        try:
-            from jax._src.compilation_cache import reset_cache
+        from jax.experimental.compilation_cache import compilation_cache
 
-            reset_cache()
-        except Exception:
-            pass
-    except Exception as e:
+        compilation_cache.reset_cache()
+    except OSError as e:
         # The cache is an optimization only — but a node running
         # without it pays every multi-minute stage compile on EVERY
         # restart, which from the outside looks identical to a slow

@@ -15,17 +15,10 @@ from __future__ import annotations
 import ctypes
 import os
 import struct
-import subprocess
-import tempfile
-from pathlib import Path
 
-_SRC = Path(__file__).resolve().parents[2] / "csrc" / "snappy.c"
-_LIB_DIR = Path(
-    os.environ.get(
-        "LODESTAR_TPU_NATIVE_DIR",
-        Path.home() / ".cache" / "lodestar_tpu" / "native",
-    )
-)
+from . import native_build
+
+_SRC = native_build.REPO / "csrc" / "snappy.c"
 
 _lib = None
 
@@ -34,26 +27,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    _LIB_DIR.mkdir(parents=True, exist_ok=True)
-    mtime = int(_SRC.stat().st_mtime)
-    path = _LIB_DIR / f"snappy_{mtime}.so"
-    if not path.exists():
-        with tempfile.TemporaryDirectory() as td:
-            tmp = Path(td) / "lib.so"
-            subprocess.run(
-                [
-                    os.environ.get("CC", "cc"),
-                    "-O2",
-                    "-shared",
-                    "-fPIC",
-                    str(_SRC),
-                    "-o",
-                    str(tmp),
-                ],
-                check=True,
-                capture_output=True,
-            )
-            os.replace(tmp, path)
+    path = native_build.build(_SRC, [os.environ.get("CC", "cc"), "-O2"])
     lib = ctypes.CDLL(str(path))
     lib.snappy_max_compressed_length.restype = ctypes.c_uint64
     lib.snappy_max_compressed_length.argtypes = [ctypes.c_uint64]

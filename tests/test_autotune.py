@@ -113,6 +113,42 @@ class TestBucketLadder:
         assert K.BUCKET_LADDER[-2:] == (512, 2048)
         assert K.bucket_size(2000) == 2048
 
+    @pytest.mark.parametrize(
+        "n, want", [(1, 128), (4, 128), (128, 128), (129, 256)]
+    )
+    def test_chip_ladder_starts_at_one_lane_block(self, n, want,
+                                                  monkeypatch):
+        from lodestar_tpu.utils import chip
+
+        monkeypatch.setattr(chip, "on_tpu", lambda: True)
+        assert K.CHIP_MIN_BUCKET == 128
+        assert K.bucket_size(n) == want
+
+    @pytest.mark.parametrize(
+        "on_chip, gate, want",
+        [(True, 256, (128,)), (True, 128, ()), (False, 256, ())],
+    )
+    def test_host_warmup_sizes_are_the_chip_retry_rungs(
+        self, on_chip, gate, want, monkeypatch
+    ):
+        from lodestar_tpu.utils import chip
+
+        monkeypatch.setattr(chip, "on_tpu", lambda: on_chip)
+        assert K.default_host_warmup_sizes(gate) == want
+
+    def test_warmup_compiles_host_rungs_first(self, monkeypatch):
+        """The retry rungs go before the multi-minute ingest rungs, and
+        each warm dispatch marks its own kind."""
+        order = []
+        monkeypatch.setattr(
+            K, "warm_bucket", lambda b, kind: order.append((kind, b))
+        )
+        monkeypatch.setattr(K, "_INGEST_WARM", set())
+        K.warmup_ingest(
+            (512,), block=True, same_message=False, host_sizes=(128,)
+        )
+        assert order == [("host", 128), ("batch", 512)]
+
     def test_set_ladder_top_below_mid_rungs_rejected(self):
         with pytest.raises(ValueError):
             K.set_ladder_top(256)

@@ -97,3 +97,23 @@ def test_same_message_retry_fanout_on_mesh(mesh):
     out, retries = asyncio.run(go())
     assert out == [i != 5 for i in range(8)]
     assert retries == 1
+
+
+@pytest.mark.slow
+def test_partly_filled_bucket_verifies_on_mesh(mesh):
+    """Nine valid sets pad to a 16-set bucket, two per chip: the chips
+    past the last live set hold padding only, and their empty share of
+    the batch equation must hold, not fail the bucket."""
+    sets = [_mk_set(5000 + i, 128 + i) for i in range(9)]
+
+    async def go():
+        v = TpuBlsVerifier(mesh=mesh)
+        ok = await v.verify_signature_sets(sets)
+        buckets = dict(v.metrics.dispatch_by_bucket)
+        await v.close()
+        return ok, buckets
+
+    ok, buckets = asyncio.run(go())
+    assert buckets == {16: 1}
+    assert ok is True
+

@@ -608,8 +608,10 @@ class TestVerifierIntegration:
                 latency_budget_ms=250,
             )
             v.attach_executor(ex)
+            # 2 sets stay under the 4-set flush target: the job waits
+            # in the rolling bucket for the deadline flush
             fut = asyncio.ensure_future(
-                v.verify_signature_sets(_mk_sets(4), batchable=True)
+                v.verify_signature_sets(_mk_sets(2), batchable=True)
             )
             # let the job land in the rolling bucket
             deadline = time.monotonic() + 2.0

@@ -582,3 +582,86 @@ class TestWarmupGate:
         finally:
             K.set_health_gate(None)
         assert K._device_dispatch_allowed() is True
+
+
+# ---------------------------------------------------------------------------
+# wave watchdog: hung device work trips it, a compile does not
+# ---------------------------------------------------------------------------
+
+
+class TestWatchdogSparesCompiles:
+    """A program's first dispatch at a bucket shape compiles inside the
+    dispatch — minutes on the chip — and the retry rungs a node's first
+    bad signature dispatches may be cold. The watchdog does not watch a
+    wave that compiles, gives a wave of many buckets the deadline of
+    each, and still trips on a dispatch that hangs once its program is
+    compiled."""
+
+    def _verify(self, monkeypatch, slow_calls, delay=0.8, jobs=None,
+                false_calls=()):
+        import asyncio
+
+        import jax.numpy as jnp
+
+        from lodestar_tpu.bls import TpuBlsVerifier
+        from lodestar_tpu.bls import kernels as K
+
+        calls = []
+
+        def device(*args):
+            calls.append(1)
+            if len(calls) in slow_calls:
+                time.sleep(delay)  # one bucket's device time
+            return jnp.asarray(len(calls) not in false_calls)
+
+        monkeypatch.setattr(K, "run_verify_batch_async", device)
+        # every program of this process starts cold
+        monkeypatch.setattr(K, "_INGEST_WARM", set())
+        tracker = _quiet_tracker()
+        if jobs is None:
+            jobs = [_mk_sets(2, msg_prefix=p) for p in (b"wd1", b"wd2")]
+
+        async def go():
+            v = TpuBlsVerifier(max_buffer_wait_ms=5, mesh=False)
+            v.attach_health(tracker, wave_timeout_s=0.25)
+            oks = [await v.verify_signature_sets(j) for j in jobs]
+            paths = dict(v.metrics.dispatch_by_path)
+            await v.close()
+            return oks, paths
+
+        oks, paths = asyncio.run(go())
+        return oks, paths, tracker
+
+    def test_first_call_compile_does_not_trip(self, monkeypatch):
+        oks, paths, tracker = self._verify(monkeypatch, slow_calls={1})
+        assert oks == [True, True]
+        assert paths["failover"] == 0 and paths["host"] == 2
+        assert tracker.watchdog_trips.get("deadline", 0) == 0
+        assert tracker.state is HealthState.online
+
+    def test_hang_of_a_compiled_program_trips(self, monkeypatch):
+        oks, paths, tracker = self._verify(monkeypatch, slow_calls={2})
+        # the hung wave's verdict came from the host oracle
+        assert oks == [True, True]
+        assert tracker.watchdog_trips.get("deadline", 0) == 1
+
+    def test_many_bucket_wave_gets_a_deadline_per_bucket(
+        self, monkeypatch
+    ):
+        """A six-set job whose bucket and per-job retry fail fans out
+        into six one-set buckets of one retry wave, 0.1 s each on the
+        device: past one bucket's 0.25 s deadline, inside six. A first
+        job compiles the one-set rung beforehand."""
+        oks, paths, tracker = self._verify(
+            monkeypatch,
+            slow_calls=set(range(4, 10)),
+            delay=0.1,
+            jobs=[
+                _mk_sets(2, msg_prefix=b"wd_warm"),
+                _mk_sets(6, msg_prefix=b"wd_fan"),
+            ],
+            false_calls={2, 3},
+        )
+        assert oks == [True, True]
+        assert paths["failover"] == 0
+        assert tracker.watchdog_trips.get("deadline", 0) == 0

@@ -155,8 +155,10 @@ class TestPersistentCacheCounters:
 
         blocker = tmp_path / "not_a_dir"
         blocker.write_text("file where the cache dir should go")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.setattr(jaxcache, "_enabled", False)
-        jaxcache.enable(cache_dir=str(blocker))  # makedirs fails
+        monkeypatch.setattr(jaxcache, "DEFAULT_DIR", str(blocker))
+        jaxcache.enable()  # makedirs fails
         assert tele.cache_errors == 1
         assert (
             "lodestar_jax_persistent_cache_errors_total 1"
@@ -164,6 +166,30 @@ class TestPersistentCacheCounters:
         )
         # enable() still latches so later callers don't retry-spam
         assert jaxcache._enabled
+
+    def test_jaxcache_enable_keeps_env_cache_dir(
+        self, tmp_path, monkeypatch
+    ):
+        """With JAX_COMPILATION_CACHE_DIR set, JAX has taken its cache
+        directory from the environment and enable() sets none of its
+        own over it."""
+        from lodestar_tpu.utils import jaxcache
+
+        outside = str(tmp_path / "placed_outside")
+        prev_dir = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        monkeypatch.setattr(jaxcache, "_enabled", False)
+        try:
+            jax.config.update("jax_compilation_cache_dir", outside)
+            jaxcache.enable()
+            assert jax.config.jax_compilation_cache_dir == outside
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev_dir)
+            from jax.experimental.compilation_cache import (
+                compilation_cache,
+            )
+
+            compilation_cache.reset_cache()
 
     def test_pending_cache_errors_absorbed_by_install(self):
         """Errors recorded before any telemetry exists (import-time

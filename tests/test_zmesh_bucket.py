@@ -128,6 +128,27 @@ class TestVerdictSemantics:
         flags[9] = False  # lives on chip 4 of 8; psum must carry it
         assert self._verify(mesh, flags) is False
 
+    @pytest.mark.parametrize("bad_live", [False, True])
+    def test_shard_of_padding_only_counts_as_good(self, mesh, bad_live):
+        """Nine live sets in a 16-set bucket: chips 5-7 hold padding
+        only, and a body that fails an empty batch (as the BLS bodies
+        do: their aggregate is the point at infinity) must not fail
+        the bucket. A failing live set still does."""
+        def body(ok, mask):
+            # padding passes inside a live batch; an empty batch fails
+            return jnp.all(ok | ~mask) & jnp.any(mask)
+
+        fn = parallel.whole_bucket_verify(mesh, body, n_args=2)
+        mask = [True] * 9 + [False] * 7
+        ok = [True] * 16
+        if bad_live:
+            ok[8] = False
+        args = (
+            parallel.shard_batch(mesh, jnp.asarray(a, dtype=bool))
+            for a in (ok, mask)
+        )
+        assert bool(jax.jit(fn)(*args)) is not bad_live
+
     def test_replicated_args_stay_whole(self, mesh):
         """An arg listed in replicated_args keeps its full shape on
         every shard (the same-message hash point)."""

@@ -249,7 +249,6 @@ class TestFusedProgramCount:
             ),
             ("_stage_miller", "f"),
             ("_stage_product", "prod"),
-            ("_stage_final", True),
             ("_stage_final_with_valid", True),
             # fused composition (3 programs)
             (
@@ -319,7 +318,7 @@ class TestFusedProgramCount:
         assert calls == [
             "_stage_prepare_batch",
             "_fused_pairing",
-            "_stage_final",
+            "_stage_final_with_valid",
         ]
 
 

@@ -1,6 +1,6 @@
 """Gossip-shaped trickle benchmark: the small-bucket steady state.
 
-BENCH_r05 measures bulk waves (16x128-set jobs); the PRODUCTION
+bench.py measures bulk waves (16x128-set jobs); the PRODUCTION
 steady state is the opposite shape — same-message groups of a few
 dozen sigs flushed by the attData-keyed queues, plus single
 aggregate-and-proof sets dripping in between. This drives

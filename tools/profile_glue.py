@@ -58,7 +58,7 @@ def main() -> None:
         prod = kernels._stage_product(f, out1[4])
         jax.block_until_ready(prod)
         t6 = time.perf_counter()
-        fin = kernels._stage_final(prod)
+        fin = kernels._stage_final_with_valid(prod, True)
         ok = bool(fin)
         t7 = time.perf_counter()
         print(

@@ -59,7 +59,7 @@ def main() -> None:
     px, py, qx, qy, full_mask = prep
     f = t("stage miller", lambda: kernels._stage_miller(px, py, qx, qy))
     prod = t("stage product", lambda: kernels._stage_product(f, full_mask))
-    t("stage final", lambda: kernels._stage_final(prod))
+    t("stage final", lambda: kernels._stage_final_with_valid(prod, True))
 
     def whole():
         b = C.scalars_to_bits(_rand_scalars(N), kernels.RAND_BITS)

@@ -58,6 +58,10 @@ TPU-attached runs: off-TPU (`--allow-cpu` smoke) every step writes
 `*_cpu.json` instead, so an emulation rehearsal can never be
 mistaken for the hardware measurement.
 
+One process per chip: this launcher never imports JAX. It asks a
+child for the platform, and every step, the in-process ones included,
+runs in a child of its own that holds the chip until it exits.
+
 Usage:
   python tools/run_real_campaign.py --dry-run
   python tools/run_real_campaign.py                 # on the TPU host
@@ -99,13 +103,25 @@ def artifact_suffix() -> str:
     return _SUFFIX
 
 
-def build_plan(args) -> list[dict]:
+def probe_suffix() -> str:
+    """artifact_suffix() of this host, asked of a child process (the
+    launcher must not bind the chip its steps need)."""
+    out = subprocess.run(
+        [PY, "-c", "import jax; print(jax.devices()[0].platform)"],
+        cwd=REPO,
+        capture_output=True,
+        text=True,
+    )
+    return "real" if out.stdout.strip() == "tpu" else "cpu"
+
+
+def build_plan(args, sfx: str) -> list[dict]:
     """The campaign as data: each step is {name, why, cmd | fn,
     artifact, needs}. Commands are plain argv lists so the dry-run
-    plan is copy-pasteable. Artifact names carry artifact_suffix():
-    on the TPU host this plan writes the *_real.json files the
-    runbook commits; off-TPU every name degrades to *_cpu.json."""
-    sfx = artifact_suffix()
+    plan is copy-pasteable. Artifact names carry the host's suffix
+    (probe_suffix): on the TPU host this plan writes the *_real.json
+    files the runbook commits; off-TPU every name degrades to
+    *_cpu.json."""
     at = args.autotune_artifact or f"AUTOTUNE_{sfx}.json"
     args.autotune_artifact = at
     return [
@@ -466,8 +482,17 @@ def step_fault_drill(args) -> dict:
     return out
 
 
+STEP_FNS = {
+    "preflight": step_preflight,
+    "autotune": step_autotune,
+    "executor_contention": step_executor_contention,
+    "fault_drill": step_fault_drill,
+}
+
+
 def run(args) -> int:
-    plan = build_plan(args)
+    sfx = probe_suffix()
+    plan = build_plan(args, sfx)
     want = (
         [s.strip() for s in args.steps.split(",") if s.strip()]
         if args.steps
@@ -482,10 +507,8 @@ def run(args) -> int:
             "campaign": "first TPU-attached measurement round",
             "runbook": "REAL_CAMPAIGN.md",
             "cwd": REPO,
-            "platform": (
-                "tpu" if artifact_suffix() == "real" else "cpu"
-            ),
-            "artifact_suffix": artifact_suffix(),
+            "platform": "tpu" if sfx == "real" else "cpu",
+            "artifact_suffix": sfx,
             "note": "artifact names reflect THIS host: on the TPU "
             "host they are *_real.json; off-TPU every step writes "
             "*_cpu.json so an emulation run can never masquerade as "
@@ -514,13 +537,6 @@ def run(args) -> int:
         print(json.dumps(out, indent=2))
         return 0
     done: set[str] = set()
-    results: dict = {}
-    fns = {
-        "preflight": step_preflight,
-        "autotune": step_autotune,
-        "executor_contention": step_executor_contention,
-        "fault_drill": step_fault_drill,
-    }
     for st in plan:
         if st["name"] not in want:
             continue
@@ -554,7 +570,19 @@ def run(args) -> int:
         print(f"==> {st['name']}", file=sys.stderr)
         try:
             if "fn" in st:
-                results[st["name"]] = fns[st["fn"]](args)
+                subprocess.run(
+                    [
+                        PY,
+                        os.path.abspath(__file__),
+                        *sys.argv[1:],
+                        "--autotune-artifact",
+                        args.autotune_artifact,
+                        "--child",
+                        st["fn"],
+                    ],
+                    cwd=REPO,
+                    check=True,
+                )
             elif st.get("stdout"):
                 with open(os.path.join(REPO, st["stdout"]), "w") as f:
                     subprocess.run(
@@ -644,7 +672,17 @@ def main() -> int:
         help="let preflight pass off-TPU (smoke only; numbers "
         "measure the CPU emulation)",
     )
-    return run(p.parse_args())
+    p.add_argument(
+        "--child",
+        choices=sorted(STEP_FNS),
+        default=None,
+        help=argparse.SUPPRESS,  # one in-process step, run by run()
+    )
+    args = p.parse_args()
+    if args.child:
+        print(json.dumps(STEP_FNS[args.child](args), default=str))
+        return 0
+    return run(args)
 
 
 if __name__ == "__main__":
